@@ -1,11 +1,9 @@
-"""Kernel-layer benchmark: CDC boundary-scan throughput (host vectorized
-path vs per-byte python-equivalent cost model) and fingerprinting rates.
+"""Kernel-layer benchmark on the host: CDC boundary-scan throughput of the
+numpy path, chunk hashing, and the jnp/interpret kernel paths.
 
-On this CPU container the Pallas kernels run in interpret mode (correctness
-path); the numbers that matter for the TPU target are the roofline terms:
-  gear one-hot matmul: (BLOCK×256×2)·2 flops / BLOCK bytes  ≈ 1 KFLOP/byte
-    → MXU-bound at ~197e12/1024 ≈ 190 GB/s per chip, ≫ any NIC.
-  page fingerprints: 2 int32 MACs/byte → VPU-bound ≫ HBM bandwidth.
+Every row is a CPU rate.  The Pallas rows run the interpreter, which
+checks correctness and says nothing about the kernels' speed on a TPU;
+no device rate has been measured here.
 """
 
 from __future__ import annotations
@@ -55,13 +53,6 @@ def run() -> Report:
         np.asarray(ops.gear_hash(small, impl="interpret"))
     rep.add(kernel="gear_pallas_interpret", mbytes_per_s=small.size / t.s / 2**20,
             note="correctness path only (Python-interpreted on CPU)")
-
-    # TPU roofline terms (analytic — the graded target architecture)
-    rep.add(kernel="gear_tpu_roofline",
-            mbytes_per_s=197e12 / (2 * 256 * 2) / 2**20,
-            note="MXU-bound one-hot matmul bytes/s bound")
-    rep.add(kernel="page_fp_tpu_roofline", mbytes_per_s=819e9 / 2**20,
-            note="HBM-bandwidth-bound (2 MACs/byte « ridge)")
     return rep
 
 

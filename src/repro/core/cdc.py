@@ -23,7 +23,7 @@ client and registry must agree on chunk boundaries byte-for-byte.
 from __future__ import annotations
 
 import dataclasses
-from typing import Iterator, List, Sequence
+from typing import Callable, Iterator, List, Sequence
 
 import numpy as np
 
@@ -110,22 +110,7 @@ def _gear_boundaries(buf: np.ndarray, params: CDCParams) -> List[int]:
         return []
     h = gear_hash_stream(buf)
     candidate = np.flatnonzero((h & np.uint32(params.mask)) == 0) + 1  # cut AFTER byte i
-    ends: List[int] = []
-    start = 0
-    ci = 0
-    m = candidate.size
-    while start < n:
-        lo = start + params.min_size
-        hi = start + params.max_size
-        # first candidate cut >= lo
-        ci = int(np.searchsorted(candidate, lo, side="left"))
-        if ci < m and candidate[ci] <= hi and candidate[ci] < n:
-            cut = int(candidate[ci])
-        else:
-            cut = min(hi, n)
-        ends.append(cut)
-        start = cut
-    return ends
+    return cuts_from_candidates(candidate, n, params)
 
 
 # ---------------------------------------------------------------------------
@@ -155,20 +140,7 @@ def _rabin_boundaries(buf: np.ndarray, params: CDCParams) -> List[int]:
             pj = pj * _RABIN_PRIME
     mask = np.uint64(params.mask)
     candidate = np.flatnonzero((h & mask) == 0) + 1
-    ends: List[int] = []
-    start = 0
-    m = candidate.size
-    while start < n:
-        lo = start + params.min_size
-        hi = start + params.max_size
-        ci = int(np.searchsorted(candidate, lo, side="left"))
-        if ci < m and candidate[ci] <= hi and candidate[ci] < n:
-            cut = int(candidate[ci])
-        else:
-            cut = min(hi, n)
-        ends.append(cut)
-        start = cut
-    return ends
+    return cuts_from_candidates(candidate, n, params)
 
 
 # ---------------------------------------------------------------------------
@@ -186,6 +158,12 @@ def chunk_boundaries(data: bytes | np.ndarray, params: CDCParams = DEFAULT_PARAM
     raise ValueError(f"unknown CDC algorithm {params.algorithm!r}")
 
 
+# A boundary scan maps (data, params) to chunk end offsets.  The host scan
+# above is the default everywhere; ``repro.kernels.ops.device_scan`` gives
+# one that runs on the accelerator with the same cut offsets.
+BoundaryScan = Callable[[bytes, CDCParams], List[int]]
+
+
 def chunk_bytes(data: bytes, params: CDCParams = DEFAULT_PARAMS) -> Iterator[bytes]:
     """Yield the chunks of ``data`` (concatenation reproduces ``data``)."""
     start = 0
@@ -201,19 +179,19 @@ def chunk_spans(data: bytes | np.ndarray, params: CDCParams = DEFAULT_PARAMS) ->
     return list(zip(starts, ends))
 
 
-def boundaries_from_mask(mask: np.ndarray, params: CDCParams) -> List[int]:
-    """Turn a per-byte candidate-boundary mask (from the Pallas kernel) into
-    min/max-size-honoring chunk end offsets.  Host-side serial pass — this is
-    the only part of CDC that is inherently sequential, and it operates on a
-    sparse candidate list, not the byte stream."""
-    n = mask.size
-    candidate = np.flatnonzero(mask) + 1
+def cuts_from_candidates(candidate: np.ndarray, n: int,
+                         params: CDCParams) -> List[int]:
+    """Chunk end offsets of an ``n``-byte stream from its sorted candidate
+    cut offsets (a cut after byte i is offset i + 1), honoring min/max size.
+    The only serial part of CDC, shared by every scan: it walks the sparse
+    candidate list, not the byte stream."""
     ends: List[int] = []
     start = 0
     m = candidate.size
     while start < n:
         lo = start + params.min_size
         hi = start + params.max_size
+        # first candidate cut >= lo
         ci = int(np.searchsorted(candidate, lo, side="left"))
         if ci < m and candidate[ci] <= hi and candidate[ci] < n:
             cut = int(candidate[ci])

@@ -348,10 +348,12 @@ class DedupStore:
     """Client/registry-side deduplicated store: chunks + recipes + accounting."""
 
     def __init__(self, directory: Optional[str] = None,
-                 cdc_params: cdc.CDCParams = cdc.DEFAULT_PARAMS):
+                 cdc_params: cdc.CDCParams = cdc.DEFAULT_PARAMS,
+                 scan: cdc.BoundaryScan = cdc.chunk_boundaries):
         self.chunks = ChunkStore(directory)
         self.recipes: Dict[str, Recipe] = {}
         self.cdc_params = cdc_params
+        self.scan = scan
         # accounting
         self.ingested_bytes = 0
         self.new_chunk_bytes = 0
@@ -360,10 +362,14 @@ class DedupStore:
     # -- ingest --------------------------------------------------------------
 
     def ingest(self, name: str, data: bytes) -> Recipe:
-        """CDC-chunk ``data``, dedup-store new chunks, record the recipe."""
+        """CDC-chunk ``data`` with the store's boundary scan, dedup-store
+        new chunks, record the recipe."""
         fps: List[bytes] = []
         sizes: List[int] = []
-        for chunk in cdc.chunk_bytes(data, self.cdc_params):
+        start = 0
+        for end in self.scan(data, self.cdc_params):
+            chunk = data[start:end]
+            start = end
             fp = hashing.chunk_fingerprint(chunk)
             if self.chunks.put(fp, chunk):
                 self.new_chunk_bytes += len(chunk)

@@ -59,6 +59,11 @@ class ImageClient:
     ``store`` / ``indexes`` / ``tag_trees`` may be donated so several
     clients (or the legacy shims) share one local state while talking
     through different transports; by default the client owns fresh state.
+
+    ``scan`` is the CDC boundary scan of a store the client creates: the
+    host scan ``cdc.chunk_boundaries`` by default, or
+    ``repro.kernels.ops.device_scan(...)`` to chunk on the accelerator.
+    Both give the same recipes, so such clients interoperate.
     """
 
     def __init__(self, transport: Optional[Transport], *,
@@ -66,6 +71,7 @@ class ImageClient:
                  indexes: Optional[Dict[str, CDMT]] = None,
                  tag_trees: Optional[Dict[str, CDMT]] = None,
                  cdc_params: cdc.CDCParams = cdc.DEFAULT_PARAMS,
+                 scan: cdc.BoundaryScan = cdc.chunk_boundaries,
                  cdmt_params: CDMTParams = DEFAULT_PARAMS,
                  directory: Optional[str] = None,
                  batch_chunks: int = 64, pipeline_depth: int = 4,
@@ -73,7 +79,7 @@ class ImageClient:
                  tracer: Tracer = NULL_TRACER):
         self.transport = transport
         self.store = store if store is not None \
-            else DedupStore(directory, cdc_params)
+            else DedupStore(directory, cdc_params, scan)
         self.cdmt_params = cdmt_params
         self.indexes: Dict[str, CDMT] = indexes if indexes is not None else {}
         # per-tag tree cache: "lineage:tag" -> CDMT.  Without it, every

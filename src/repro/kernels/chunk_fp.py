@@ -7,10 +7,14 @@ independent bases.  Pages whose 64-bit fp pair matches a stored page are
 *candidate* duplicates — the host confirms with blake2b before dropping any
 byte, so the kernel only needs to be collision-*rare*, not collision-free.
 
-Mapping to TPU: the weighted sum is elementwise-multiply + row reduction on
-the VPU in int32 (XLA int32 wraps ⇒ arithmetic is exactly mod 2^32 — no
-fp rounding concerns, unlike an MXU matmul formulation).  Grid is 1-D over
-page tiles; weights are a broadcast operand resident in VMEM.
+Layout that Mosaic compiles: a grid step takes a ``(PAGE_TILE, S)`` uint8
+block of whole pages (32 rows: one uint8 tile tall) and the two weight
+vectors as one lane-dense ``(2, S)`` int32 operand, resident across steps.
+Each fingerprint is an elementwise multiply by one weight *row* and a lane
+reduction, in int32 on the VPU: wraparound is exactly mod 2^32, with no
+rounding question as an MXU formulation would raise.  No column of the
+weights is ever gathered.  The two sums land in the two columns of a
+``(PAGE_TILE, 2)`` output block.
 """
 
 from __future__ import annotations
@@ -19,24 +23,22 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 
 from .ref import fp_weights, _squared_weights
 
-PAGE_TILE = 256        # pages per grid step
+PAGE_TILE = 32         # pages per grid step: one uint8 (32, 128k) tile row
 
 
 def _chunk_fp_kernel(pages_ref, w_ref, fp_ref):
     pages = pages_ref[...].astype(jnp.int32)          # (PAGE_TILE, S)
-    w = w_ref[...]                                    # (S, 2) int32
-    fp1 = jnp.sum(pages * w[None, :, 0], axis=1, dtype=jnp.int32)
-    fp2 = jnp.sum(pages * w[None, :, 1], axis=1, dtype=jnp.int32)
-    fp_ref[...] = jnp.stack([fp1, fp2], axis=-1)      # (PAGE_TILE, 2)
+    for k in range(2):                                # one base per column
+        fp_ref[:, k:k + 1] = jnp.sum(pages * w_ref[k:k + 1, :], axis=1,
+                                     keepdims=True, dtype=jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def page_fingerprint_pallas(pages: jax.Array, *, interpret: bool = True) -> jax.Array:
+def page_fingerprint_pallas(pages: jax.Array, *, interpret: bool) -> jax.Array:
     """Fingerprint (n_pages, page_size) uint8 pages → (n_pages, 2) int32.
 
     ``n_pages`` must be a multiple of PAGE_TILE (ops.py pads with zero pages
@@ -45,14 +47,14 @@ def page_fingerprint_pallas(pages: jax.Array, *, interpret: bool = True) -> jax.
     n_pages, page_size = pages.shape
     assert n_pages % PAGE_TILE == 0, "pad pages to PAGE_TILE (see ops.py)"
     w = jnp.stack([jnp.asarray(fp_weights(page_size)),
-                   jnp.asarray(_squared_weights(page_size))], axis=1)
+                   jnp.asarray(_squared_weights(page_size))])   # (2, S)
 
     return pl.pallas_call(
         _chunk_fp_kernel,
         grid=(n_pages // PAGE_TILE,),
         in_specs=[
             pl.BlockSpec((PAGE_TILE, page_size), lambda i: (i, 0)),
-            pl.BlockSpec((page_size, 2), lambda i: (0, 0)),
+            pl.BlockSpec((2, page_size), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((PAGE_TILE, 2), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_pages, 2), jnp.int32),

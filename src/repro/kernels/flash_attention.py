@@ -75,7 +75,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
 @functools.partial(jax.jit, static_argnames=("causal", "scale", "interpret"))
 def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
                            causal: bool = True, scale: float | None = None,
-                           interpret: bool = True) -> jax.Array:
+                           interpret: bool) -> jax.Array:
     """Fused attention.  q/k/v: (BH, S, D) with S % 128 == 0, matched heads.
 
     Returns (BH, S, D) in q.dtype; fp32 accumulation inside.

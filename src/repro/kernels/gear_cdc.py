@@ -1,26 +1,34 @@
 """Pallas TPU kernel: content-defined-chunking boundary scan (gear hash).
 
-The paper's CDC hot loop (Sec. III-A, VI-D) is a byte-serial rolling hash —
-hostile to a TPU.  Two adaptations (DESIGN.md §4) make it TPU-native:
+The paper's CDC hot loop (Sec. III-A, VI-D) is a byte-serial rolling hash.
+Two rewrites make it a tiled vector program that Mosaic compiles:
 
-1. **Table lookup → one-hot matmul.**  ``G[byte]`` over a 256-entry table is
-   a gather (slow on TPU).  Instead each byte becomes a one-hot row of a
-   ``(sub, 256)`` matrix and the lookup is a ``(sub,256) @ (256,2)``
-   matmul on the MXU.  The uint32 gear values are split into two exact
-   16-bit halves so fp32 MXU accumulation is exact (one-hot rows select a
-   single entry; |half| < 2^16 < 2^24).
+1. **Serial recurrence → bounded convolution.**  ``h_i = 2 h_{i-1} + g_i``
+   (mod 2^32) forgets a term after 32 doublings, so
+   ``h_i = Σ_{j<32} 2^j g_{i-j}``.  The kernel builds that sum in five
+   doubling steps, ``a ← a + (a shifted by k) << k`` for k = 1, 2, 4, 8,
+   16, in int32 (wraparound is mod 2^32).
 
-2. **Serial recurrence → bounded convolution.**  ``h_i = 2 h_{i-1} + g_i``
-   (mod 2^32) has bounded memory: after 32 doublings a term leaves the
-   register, so ``h_i = Σ_{j<32} 2^j g_{i-j}`` — a 32-tap convolution,
-   computed with static shifted adds on the VPU (int32 wraparound = mod
-   2^32).  Cross-block dependence is only a 31-byte halo, passed as a
-   second blocked operand, so grid steps are fully independent.
+2. **A flat stream → (rows, 128) tiles.**  Byte ``p`` of a window lives at
+   ``(p // 128, p % 128)``.  "Shifted by k" is then two aligned rotations:
+   a lane roll by k, and for lanes ``< k`` the same roll of the row above
+   (a sublane roll by one).  No unaligned slice or 1-D concatenate remains.
 
-Grid: 1-D over byte-stream tiles of ``BLOCK`` (16 KiB).  VMEM per step:
-in/out tiles ~80 KiB + one (SUB=2048, 256) f32 one-hot scratch of 2 MiB —
-well inside the ~16 MiB/core budget; sub-tiling keeps the one-hot from
-scaling with BLOCK.
+The gear lookup ``G[byte]`` is a chain of 256 compare/selects against
+int32 constants on the VPU.  It is exact by construction: no MXU pass, so
+no question of how many mantissa bits a matmul keeps.
+
+Layout: a window of ``n`` bytes (a multiple of ``BLOCK``) is a
+``(n // 128, 128)`` uint8 array.  Grid step ``i`` hashes ``BLOCK_ROWS``
+rows.  Its halo is a second view of the same array: the whole aligned
+uint8 tile ``(32, 128)`` just before the block.  Step 0 reads that tile
+from ``prev`` instead, the 4096 bytes that precede the window in the
+stream; an SMEM flag says whether the window starts the stream, in which
+case the halo contributes zero.  Inside a step a ``fori_loop`` walks
+``SUB_ROWS``-row sub-tiles: gear values go to a VMEM scratch with one
+(8, 128) int32 tile of look-back ahead of them, so every load and store is
+tile aligned.  VMEM per step: two 64 KiB uint8 input buffers, two 256 KiB
+int32 output buffers, and a 260 KiB scratch — about 1 MiB.
 """
 
 from __future__ import annotations
@@ -31,83 +39,92 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.cdc import GEAR_WINDOW, gear_table
+from repro.core.cdc import gear_table
 
-BLOCK = 16384          # bytes per grid step
-SUB = 2048             # one-hot sub-tile rows (VMEM: (SUB,256) f32 = 2 MiB)
-HALO = GEAR_WINDOW     # 32 trailing bytes of the previous block
+LANES = 128
+TILE_ROWS = 32                  # rows of one uint8 (32, 128) tile
+TILE_BYTES = TILE_ROWS * LANES  # 4096: the halo each window carries
+BLOCK_ROWS = 512
+BLOCK = BLOCK_ROWS * LANES      # 64 KiB of stream per grid step
+SUB_ROWS = 32                   # rows per inner-loop sub-tile
+LOOKBACK = 8                    # int32 rows (one tile) kept ahead of a sub-tile
 
-
-def _gear_table_halves() -> jax.Array:
-    """(256, 2) f32: [hi16, lo16] of each gear entry — exact in fp32."""
-    g = gear_table()
-    hi = (g >> 16).astype(np.float32)
-    lo = (g & 0xFFFF).astype(np.float32)
-    return jnp.stack([jnp.asarray(hi), jnp.asarray(lo)], axis=1)
+_GEAR_I32 = [int(v) for v in gear_table().view(np.int32)]
 
 
-def _gear_cdc_kernel(bytes_ref, halo_ref, table_ref, hash_ref):
+def _lookup(x: jax.Array) -> jax.Array:
+    """``G[x]`` for int32 byte values, as 256 compare/selects."""
+    g = jnp.zeros_like(x)
+    for v, t in enumerate(_GEAR_I32):
+        g = jnp.where(x == v, jnp.int32(t), g)
+    return g
+
+
+def _shift(a: jax.Array, k: int) -> jax.Array:
+    """``a`` moved ``k`` (< 128) positions later along the row-major stream."""
+    rolled = pltpu.roll(a, k, 1)                 # [r, c] <- [r, c-k mod 128]
+    above = pltpu.roll(rolled, 1, 0)             # [r, c] <- rolled[r-1, c]
+    lane = jax.lax.broadcasted_iota(jnp.int32, a.shape, 1)
+    return jnp.where(lane >= k, rolled, above)
+
+
+def _gear_cdc_kernel(first_ref, data_ref, halo_ref, prev_ref, hash_ref, g_ref):
     """One grid step: rolling gear hash of BLOCK bytes (uint32 bits in int32)."""
-    data = jnp.concatenate([halo_ref[...], bytes_ref[...]], axis=0)
-    n = BLOCK + HALO
-    table = table_ref[...]                                    # (256, 2) f32
-    data_i32 = data.astype(jnp.int32)
+    i = pl.program_id(0)
+    before = jnp.where(i == 0, prev_ref[...].astype(jnp.int32),
+                       halo_ref[...].astype(jnp.int32))
+    g_before = _lookup(before[TILE_ROWS - LOOKBACK:])
+    # the stream's first window has no predecessor: its halo is not stream
+    # bytes and contributes nothing (h_i sums only over positions >= 0)
+    at_start = jnp.logical_and(i == 0, first_ref[0] != 0)
+    g_ref[0:LOOKBACK, :] = jnp.where(at_start, 0, g_before)
 
-    # --- 1. gear lookup via one-hot matmul (MXU), per sub-tile -------------
-    def lookup(sub):                                          # (m,) int32
-        onehot = (sub[:, None] == jax.lax.broadcasted_iota(
-            jnp.int32, (sub.shape[0], 256), 1)).astype(jnp.float32)
-        halves = jnp.dot(onehot, table,
-                         preferred_element_type=jnp.float32)  # (m, 2)
-        hi = halves[:, 0].astype(jnp.int32)
-        lo = halves[:, 1].astype(jnp.int32)
-        return (hi << 16) + lo                                # exact uint32 bits
+    def body(s, carry):
+        r0 = pl.multiple_of(s * SUB_ROWS, SUB_ROWS)
+        x = data_ref[pl.ds(r0, SUB_ROWS), :].astype(jnp.int32)
+        g_ref[pl.ds(r0 + LOOKBACK, SUB_ROWS), :] = _lookup(x)
+        a = g_ref[pl.ds(r0, LOOKBACK + SUB_ROWS), :]
+        for k in (1, 2, 4, 8, 16):               # window 1 -> 2 -> ... -> 32
+            a = a + (_shift(a, k) << k)
+        hash_ref[pl.ds(r0, SUB_ROWS), :] = a[LOOKBACK:]
+        return carry
 
-    g_parts = [lookup(data_i32[s0:min(s0 + SUB, n)])          # static unroll
-               for s0 in range(0, n, SUB)]
-    g = jnp.concatenate(g_parts, axis=0)                      # (BLOCK+HALO,)
-
-    # Block 0 has no predecessor: its halo is padding, not stream bytes, so
-    # its gear contributions must be zero (ref semantics: h_i sums only
-    # over existing positions i-j >= 0).
-    first = pl.program_id(0) == 0
-    idx = jax.lax.broadcasted_iota(jnp.int32, (BLOCK + HALO,), 0)
-    g = jnp.where(jnp.logical_and(first, idx < HALO), 0, g)
-
-    # --- 2. 32-tap convolution with weights 2^j (VPU shifted adds) ---------
-    h = jnp.zeros((BLOCK,), dtype=jnp.int32)
-    for j in range(GEAR_WINDOW):
-        # output position i (block coords) reads g[HALO + i - j]
-        h = h + (g[HALO - j: HALO - j + BLOCK] << j)
-    hash_ref[...] = h
+    jax.lax.fori_loop(0, BLOCK_ROWS // SUB_ROWS, body, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def gear_hash_pallas(data: jax.Array, *, interpret: bool = True) -> jax.Array:
-    """Rolling gear hash of a uint8 stream via the Pallas kernel.
+def gear_hash_pallas(window: jax.Array, prev: jax.Array, first: jax.Array, *,
+                     interpret: bool) -> jax.Array:
+    """Rolling gear hash of one window of a uint8 stream.
 
-    ``data`` length must be a multiple of BLOCK (ops.py pads).  Returns
-    uint32 hashes, bit-identical to ``ref.gear_hash_ref``.
+    ``window``: (n,) uint8 with ``n`` a multiple of BLOCK.  ``prev``:
+    (TILE_BYTES,) uint8, the stream bytes just before the window.
+    ``first``: (1,) int32, nonzero when the window starts the stream (then
+    ``prev`` is ignored).  Returns (n,) uint32, bit-identical to
+    ``cdc.gear_hash_stream`` of the stream at these positions.
     """
-    n = data.shape[0]
-    assert n % BLOCK == 0, "pad to BLOCK first (see ops.gear_boundary_mask)"
-    n_blocks = n // BLOCK
-    blocks = data.reshape(n_blocks, BLOCK)
-    # halo operand: the 32 bytes preceding each block (zeros for block 0)
-    halo_rows = jnp.concatenate(
-        [jnp.zeros((1, HALO), jnp.uint8), blocks[:-1, -HALO:]], axis=0)
-
+    n = window.shape[0]
+    assert n % BLOCK == 0, "pad the window to BLOCK (see ops.gear_hash)"
+    rows = n // LANES
+    tiles_per_block = BLOCK_ROWS // TILE_ROWS
     out = pl.pallas_call(
         _gear_cdc_kernel,
-        grid=(n_blocks,),
+        grid=(rows // BLOCK_ROWS,),
         in_specs=[
-            pl.BlockSpec((BLOCK,), lambda i: (i,)),
-            pl.BlockSpec((HALO,), lambda i: (i,)),
-            pl.BlockSpec((256, 2), lambda i: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
+            pl.BlockSpec((TILE_ROWS, LANES),
+                         lambda i: (jnp.maximum(i * tiles_per_block - 1, 0), 0)),
+            pl.BlockSpec((TILE_ROWS, LANES), lambda i: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((BLOCK,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n,), jnp.int32),
+        out_specs=pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((rows, LANES), jnp.int32),
+        scratch_shapes=[pltpu.VMEM((LOOKBACK + BLOCK_ROWS, LANES), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(blocks.reshape(-1), halo_rows.reshape(-1), table := _gear_table_halves())
-    return jax.lax.bitcast_convert_type(out, jnp.uint32)
+    )(first, window.reshape(rows, LANES), window.reshape(rows, LANES),
+      prev.reshape(TILE_ROWS, LANES))
+    return jax.lax.bitcast_convert_type(out.reshape(n), jnp.uint32)

@@ -1,9 +1,10 @@
 """Pure-jnp oracles for every Pallas kernel.
 
-These are the semantics contracts: tests sweep shapes/dtypes and
-``assert_allclose`` each kernel (run with ``interpret=True`` on CPU) against
-the functions here.  They are also the CPU/debug execution path selected by
-``repro.kernels.ops`` when no TPU is present.
+These are the test oracle and the semantics contract: tests sweep
+shapes/dtypes and compare each kernel (``impl="interpret"`` on the CPU,
+``impl="pallas"`` on a TPU) with the functions here.  ``ops`` runs them only
+when a caller names ``impl="ref"``; nothing selects them in place of a
+kernel.
 """
 
 from __future__ import annotations

@@ -25,6 +25,7 @@ import jax
 
 from repro.configs.base import SHAPES, get_config, list_archs
 from repro.launch import hlo_cost, mesh as mesh_lib
+from repro.launch.compile_cache import configure_compile_cache
 from repro.launch.cells import build_cell, lower_cell
 
 RESULTS_DIR = os.path.join(os.path.dirname(__file__),
@@ -70,8 +71,6 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
                               - ma.alias_size_in_bytes),
         }
         ca = compiled.cost_analysis() or {}
-        if isinstance(ca, (list, tuple)):      # older jax: one dict per device
-            ca = ca[0] if ca else {}
         rec["xla_cost"] = {k: float(v) for k, v in ca.items()
                            if k in ("flops", "bytes accessed", "transcendentals")}
 
@@ -162,6 +161,7 @@ def main() -> None:
     ap.add_argument("--out-dir", default=os.path.normpath(RESULTS_DIR))
     ap.add_argument("--skip-existing", action="store_true")
     args = ap.parse_args()
+    configure_compile_cache()
 
     rules = json.loads(args.rules) if args.rules else None
     cfg_overrides = json.loads(args.cfg) if args.cfg else None

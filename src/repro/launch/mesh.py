@@ -32,14 +32,9 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
     """Arbitrary mesh (tests / hillclimb sweeps).  Auto axis types: the
-    framework shards via PartitionSpecs + logical-axis constraints.
-    ``AxisType`` only exists on newer jax; Auto is the default there anyway,
-    so older versions just omit the kwarg."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
+    framework shards via PartitionSpecs + logical-axis constraints."""
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def require_devices(n: int) -> None:

@@ -12,6 +12,7 @@ import jax
 import numpy as np
 
 from repro.configs.base import get_config, list_archs
+from repro.launch.compile_cache import configure_compile_cache
 from repro.models.api import Model
 from repro.serving import Request, ServeConfig, ServingEngine
 
@@ -26,6 +27,7 @@ def main() -> None:
     ap.add_argument("--new-tokens", type=int, default=8)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    configure_compile_cache()
 
     model = Model(get_config(args.arch, reduced=args.reduced))
     params = model.init_params(jax.random.PRNGKey(args.seed))

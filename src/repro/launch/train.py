@@ -22,6 +22,7 @@ from repro.checkpoint import CheckpointConfig
 from repro.configs.base import get_config, list_archs
 from repro.core.registry import Registry
 from repro.data import DataConfig
+from repro.launch.compile_cache import configure_compile_cache
 from repro.models.api import Model
 from repro.optim import AdamWConfig
 from repro.runtime.trainer import Trainer, TrainerConfig
@@ -45,6 +46,7 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
+    configure_compile_cache()
 
     model = Model(get_config(args.arch, reduced=args.reduced))
     print(f"arch={args.arch} reduced={args.reduced} "

@@ -44,15 +44,11 @@ def pipeline_forward(stage_params, x_microbatches, stage_ids,
     ``axis`` holds its own stage (leading dim 1 per device).
     ``x_microbatches``: (M, mb, S_len, d) activations, replicated along
     ``axis``.  ``stage_ids``: the (S,) iota sharded P(axis) — its (1,)
-    per-device slice is this device's stage index (compat.axis_index_input;
-    ``jax.lax.axis_index`` lowers to a PartitionId HLO that old-jax SPMD
-    partitioning rejects inside partial-auto shard_map).  Returns
-    (M, mb, S_len, d) outputs (valid on the LAST stage; callers read them
-    there).
+    per-device slice is this device's stage index
+    (compat.axis_index_input).  Returns (M, mb, S_len, d) outputs (valid
+    on the LAST stage; callers read them there).
     """
-    from repro.parallel.compat import (LEGACY_PARTIAL_AUTO, axis_size,
-                                       shift_up, unrolled_scan)
-    n_stages = axis_size(axis)
+    n_stages = jax.lax.axis_size(axis)
     stage_id = stage_ids[0]
     m = x_microbatches.shape[0]
 
@@ -65,64 +61,36 @@ def pipeline_forward(stage_params, x_microbatches, stage_ids,
         def body(carry, lp):
             out, _ = f(lp, carry)
             return out, None
-        out, _ = unrolled_scan(body, h, local)
+        out, _ = jax.lax.scan(body, h, local)
         return out
 
     n_ticks = m + n_stages - 1
     zero = jnp.zeros_like(x_microbatches[0])
+    # ring handoff: stage i receives from stage i-1; stage 0 receives zeros
+    ring = [(i, i + 1) for i in range(n_stages - 1)]
 
-    if not LEGACY_PARTIAL_AUTO:
-        # indexed schedule: O(one microbatch) work per tick — stage 0 reads
-        # x[t], the last stage writes outputs[t-(S-1)] in place
-        outputs0 = jnp.zeros_like(x_microbatches)
+    # indexed schedule: O(one microbatch) work per tick — stage 0 reads
+    # x[t], the last stage writes outputs[t-(S-1)] in place
+    outputs0 = jnp.zeros_like(x_microbatches)
 
-        def tick(state, t):
-            inflight, outputs = state
-            mb_idx = jnp.clip(t, 0, m - 1)
-            inject = jax.lax.select(t < m, x_microbatches[mb_idx], zero)
-            h_in = jnp.where(stage_id == 0, inject, inflight)
-            h_out = run_stage(h_in)
-            # pass to the next stage (ring permute; last→first slot unused)
-            handoff = shift_up(h_out, axis, stage_id)
-            emit_idx = t - (n_stages - 1)
-            valid = jnp.logical_and(stage_id == n_stages - 1, emit_idx >= 0)
-            outputs = jax.lax.cond(
-                valid,
-                lambda o: jax.lax.dynamic_update_index_in_dim(
-                    o, h_out, jnp.clip(emit_idx, 0, m - 1), 0),
-                lambda o: o, outputs)
-            return (handoff, outputs), None
+    def tick(state, t):
+        inflight, outputs = state
+        mb_idx = jnp.clip(t, 0, m - 1)
+        inject = jax.lax.select(t < m, x_microbatches[mb_idx], zero)
+        h_in = jnp.where(stage_id == 0, inject, inflight)
+        h_out = run_stage(h_in)
+        handoff = jax.lax.ppermute(h_out, axis, ring)
+        emit_idx = t - (n_stages - 1)
+        valid = jnp.logical_and(stage_id == n_stages - 1, emit_idx >= 0)
+        outputs = jax.lax.cond(
+            valid,
+            lambda o: jax.lax.dynamic_update_index_in_dim(
+                o, h_out, jnp.clip(emit_idx, 0, m - 1), 0),
+            lambda o: o, outputs)
+        return (handoff, outputs), None
 
-        (_, outputs), _ = jax.lax.scan(tick, (zero, outputs0),
-                                       jnp.arange(n_ticks))
-    else:
-        # FIFO schedule for old jax, whose partial-auto partitioner crashes
-        # on every loop-index-dependent pattern above (x[t]-style gathers,
-        # DynamicUpdateSlice/one-hot writes, even hoisted device-varying
-        # booleans closed over by a scan body): stage 0 pops its next
-        # microbatch off the front of a shifting feed queue (zeros after the
-        # first m ticks = drain phase) and the last stage pushes h_out onto
-        # the back of a length-m emit queue, so the body uses only static
-        # slices/concats.  The last stage emits microbatch t-(S-1) at tick
-        # t, so after m + S - 1 ticks the queue holds microbatches 0..m-1.
-        # Costs O(m) copies per tick — acceptable on the compat path only.
-        def tick(state, _):
-            inflight, feed, outputs = state
-            is_first = stage_id == 0        # must stay INSIDE the loop body
-            is_last = stage_id == n_stages - 1
-            inject = feed[0]
-            feed = jnp.concatenate([feed[1:], feed[:1] * 0])
-            h_in = jnp.where(is_first, inject, inflight)
-            h_out = run_stage(h_in)
-            # ring shift via compat.shift_up's psum-gather emulation
-            handoff = shift_up(h_out, axis, stage_id)
-            emit = jnp.where(is_last, h_out, zero)
-            outputs = jnp.concatenate([outputs[1:], emit[None]])
-            return (handoff, feed, outputs), None
-
-        state0 = (zero, x_microbatches, jnp.zeros_like(x_microbatches))
-        (_, _, outputs), _ = unrolled_scan(tick, state0, None,
-                                           length=n_ticks)
+    (_, outputs), _ = jax.lax.scan(tick, (zero, outputs0),
+                                   jnp.arange(n_ticks))
     # only the last stage emitted (zeros elsewhere): psum replicates its
     # outputs across the pipeline axis so out_specs=P() is truly replicated
     return jax.lax.psum(outputs, axis)

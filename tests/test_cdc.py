@@ -77,5 +77,6 @@ def test_mask_to_boundaries_matches_direct():
     data = np.frombuffer(_rand(50_000, seed=7), dtype=np.uint8)
     h = cdc.gear_hash_stream(data)
     mask = (h & np.uint32(PARAMS.mask)) == 0
-    assert cdc.boundaries_from_mask(mask, PARAMS) == \
+    candidate = np.flatnonzero(mask) + 1
+    assert cdc.cuts_from_candidates(candidate, data.size, PARAMS) == \
         cdc.chunk_boundaries(data, PARAMS)

@@ -7,6 +7,7 @@ covers the protocol pieces themselves.
 """
 
 import threading
+import time
 
 import pytest
 
@@ -165,6 +166,18 @@ def sock_env():
     sock_srv.stop()
 
 
+def _metered_egress(sock_srv, want, timeout=5.0):
+    """The server's stats once its egress meter reads ``want`` (or at the
+    deadline).  The server meters a write after ``sendall`` returns, which
+    can be after the client has read the whole response."""
+    deadline = time.monotonic() + timeout
+    s = sock_srv.snapshot()
+    while s.egress_bytes != want and time.monotonic() < deadline:
+        time.sleep(0.005)
+        s = sock_srv.snapshot()
+    return s
+
+
 class TestSocketServer:
     def test_pull_and_materialize(self, sock_env):
         srv, sock_srv, versions, connect = sock_env
@@ -195,14 +208,17 @@ class TestSocketServer:
         """Socket meters == frame meters + exactly the envelope bytes."""
         srv, sock_srv, versions, connect = sock_env
         t = connect()
-        s0, f0 = sock_srv.snapshot(), srv.snapshot()
+        info = len(wire.encode_info(srv.max_batch_chunks))
+        s0 = _metered_egress(sock_srv, wire.response_envelope_bytes([info]))
+        f0 = srv.snapshot()
         idx, nbytes = t.get_index("app", "v1")
-        s1, f1 = sock_srv.snapshot(), srv.snapshot()
+        f1 = srv.snapshot()
         frame_len = f1.egress_bytes - f0.egress_bytes
         req_len = wire.request_envelope_bytes("app", "v1", [])
+        want_egress = wire.response_envelope_bytes([frame_len])
+        s1 = _metered_egress(sock_srv, s0.egress_bytes + want_egress)
         assert s1.ingress_bytes - s0.ingress_bytes == req_len
-        assert s1.egress_bytes - s0.egress_bytes \
-            == wire.response_envelope_bytes([frame_len])
+        assert s1.egress_bytes - s0.egress_bytes == want_egress
         assert nbytes == req_len + wire.response_envelope_bytes([frame_len])
 
     def test_tags_over_socket_metered(self, sock_env):
