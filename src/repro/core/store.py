@@ -32,6 +32,8 @@ from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.obs import NULL_TRACER, Tracer
+
 from . import cdc, hashing
 from .errors import DeliveryError
 from .journal import fsync_dir
@@ -349,11 +351,13 @@ class DedupStore:
 
     def __init__(self, directory: Optional[str] = None,
                  cdc_params: cdc.CDCParams = cdc.DEFAULT_PARAMS,
-                 scan: cdc.BoundaryScan = cdc.chunk_boundaries):
+                 scan: cdc.BoundaryScan = cdc.chunk_boundaries,
+                 tracer: Tracer = NULL_TRACER):
         self.chunks = ChunkStore(directory)
         self.recipes: Dict[str, Recipe] = {}
         self.cdc_params = cdc_params
         self.scan = scan
+        self.tracer = tracer
         # accounting
         self.ingested_bytes = 0
         self.new_chunk_bytes = 0
@@ -363,20 +367,28 @@ class DedupStore:
 
     def ingest(self, name: str, data: bytes) -> Recipe:
         """CDC-chunk ``data`` with the store's boundary scan, dedup-store
-        new chunks, record the recipe."""
-        fps: List[bytes] = []
-        sizes: List[int] = []
-        start = 0
-        for end in self.scan(data, self.cdc_params):
-            chunk = data[start:end]
-            start = end
-            fp = hashing.chunk_fingerprint(chunk)
-            if self.chunks.put(fp, chunk):
-                self.new_chunk_bytes += len(chunk)
-            else:
-                self.dup_chunk_bytes += len(chunk)
-            fps.append(fp)
-            sizes.append(len(chunk))
+        new chunks, record the recipe.
+
+        Three passes: the cuts, then every chunk's fingerprint (a
+        ``commit.hash`` span), then the puts (``commit.put``), each span
+        carrying its counts."""
+        cuts = self.scan(data, self.cdc_params)
+        bounds = list(zip([0] + cuts[:-1], cuts))
+        sizes = [end - start for start, end in bounds]
+        with self.tracer.span("commit.hash", chunks=len(bounds),
+                              bytes=len(data)), memoryview(data) as view:
+            fps = [hashing.chunk_fingerprint(view[start:end])
+                   for start, end in bounds]
+        with self.tracer.span("commit.put") as sp:
+            new_chunks = new_bytes = 0
+            for fp, (start, end) in zip(fps, bounds):
+                if self.chunks.put(fp, data[start:end]):
+                    new_chunks += 1
+                    new_bytes += end - start
+            sp.annotate(new_chunks=new_chunks, new_bytes=new_bytes,
+                        dup_bytes=len(data) - new_bytes)
+        self.new_chunk_bytes += new_bytes
+        self.dup_chunk_bytes += len(data) - new_bytes
         self.ingested_bytes += len(data)
         recipe = Recipe(name=name, fps=fps, sizes=sizes)
         self.recipes[name] = recipe

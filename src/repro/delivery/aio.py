@@ -184,6 +184,15 @@ class AsyncRegistryServer:
         self._m_queue = m.gauge(
             "async_queue_depth",
             "handler jobs queued for a worker-pool thread").labels()
+        self._m_wait = m.counter(
+            "async_queue_wait_seconds_total",
+            "seconds handler jobs waited from submission to a worker "
+            "thread").labels()
+        self._m_job = m.counter(
+            "async_job_seconds_total",
+            "seconds handler jobs ran on worker threads").labels()
+        self._m_jobs = m.counter(
+            "async_jobs_total", "handler jobs run on the worker pool").labels()
         lat = m.histogram(
             "async_request_seconds",
             "admission-to-last-byte stream latency (queueing included)",
@@ -413,12 +422,20 @@ class AsyncRegistryServer:
 
     async def _run(self, fn, *args):
         """One handler job on the worker pool; the queue-depth gauge
-        counts jobs submitted but not yet started."""
+        counts jobs submitted but not yet started, and the job counters
+        add its wait for a thread and its run on one."""
         self._m_queue.inc()
+        submitted = time.perf_counter()
 
         def job():
             self._m_queue.dec()
-            return fn(*args)
+            started = time.perf_counter()
+            self._m_wait.inc(started - submitted)
+            try:
+                return fn(*args)
+            finally:
+                self._m_job.inc(time.perf_counter() - started)
+                self._m_jobs.inc()
 
         return await self._loop.run_in_executor(self._pool, job)
 

@@ -25,9 +25,12 @@ Observability: the client adopts its transport's
 :class:`~repro.obs.MetricsRegistry` (so one snapshot covers the client's
 ``client_*`` histograms *and* the transport's byte/latency series) and
 accepts a :class:`~repro.obs.Tracer` — disabled by default, near-zero cost
-— that records one span tree per pull (``pull`` → ``plan_pull`` /
-``execute`` → per-batch ``fetch_batch`` children, attributed across the
-pipeline's pool threads via explicit parent hand-off).
+— that records one span tree per pull (``pull`` → ``plan_pull`` (its
+``plan_pull.index`` / ``plan_pull.recipe`` fetches, the rest is the
+compare) / ``execute`` → per-batch ``fetch_batch`` children, attributed
+across the pipeline's pool threads via explicit parent hand-off) and one
+per commit (``commit`` → ``scan`` where the scan is traced,
+``commit.hash``, ``commit.put``, ``commit.cdmt``).
 """
 
 from __future__ import annotations
@@ -38,7 +41,7 @@ from concurrent.futures import ThreadPoolExecutor
 from typing import Dict, List, Optional, Sequence
 
 from repro.core import cdc
-from repro.core.cdmt import (CDMT, CDMTParams, DEFAULT_PARAMS,
+from repro.core.cdmt import (CDMT, BuildStats, CDMTParams, DEFAULT_PARAMS,
                              iter_missing_leaves)
 from repro.core.errors import DeliveryError
 from repro.core.store import DedupStore, Recipe
@@ -79,7 +82,7 @@ class ImageClient:
                  tracer: Tracer = NULL_TRACER):
         self.transport = transport
         self.store = store if store is not None \
-            else DedupStore(directory, cdc_params, scan)
+            else DedupStore(directory, cdc_params, scan, tracer=tracer)
         self.cdmt_params = cdmt_params
         self.indexes: Dict[str, CDMT] = indexes if indexes is not None else {}
         # per-tag tree cache: "lineage:tag" -> CDMT.  Without it, every
@@ -135,14 +138,24 @@ class ImageClient:
     # api-boundary
     def commit(self, lineage: str, tag: str, data: bytes) -> Recipe:
         """Chunk + locally store a new artifact version, build local CDMT
-        (incrementally against the lineage head when one exists)."""
-        recipe = self.store.ingest(f"{lineage}:{tag}", data)
-        head = self.indexes.get(lineage)
-        if head is not None and head.root is not None:
-            tree = CDMT.build_incremental(head, recipe.fps,
-                                          params=self.cdmt_params)
-        else:
-            tree = CDMT.build(recipe.fps, params=self.cdmt_params)
+        (incrementally against the lineage head when one exists).
+
+        Traced as a ``commit`` span over the store's ingest (``scan``,
+        ``commit.hash``, ``commit.put``) and the ``commit.cdmt`` build."""
+        with self.tracer.span("commit", lineage=lineage, tag=tag,
+                              bytes=len(data)):
+            recipe = self.store.ingest(f"{lineage}:{tag}", data)
+            head = self.indexes.get(lineage)
+            stats = BuildStats()
+            with self.tracer.span("commit.cdmt") as sp:
+                if head is not None and head.root is not None:
+                    tree = CDMT.build_incremental(head, recipe.fps,
+                                                  params=self.cdmt_params,
+                                                  stats=stats)
+                else:
+                    tree = CDMT.build(recipe.fps, params=self.cdmt_params,
+                                      stats=stats)
+                sp.annotate(nodes_created=stats.nodes_created)
         self.indexes[lineage] = tree
         self.tag_trees[f"{lineage}:{tag}"] = tree
         return recipe
@@ -194,8 +207,12 @@ class ImageClient:
 
     def _plan_pull(self, lineage: str, tag: str) -> PullPlan:
         transport = self._require_transport()
-        index, index_bytes = transport.get_index(lineage, tag)
-        recipe, recipe_bytes = transport.get_recipe(lineage, tag)
+        with self.tracer.span("plan_pull.index") as sp:
+            index, index_bytes = transport.get_index(lineage, tag)
+            sp.annotate(bytes=index_bytes)
+        with self.tracer.span("plan_pull.recipe") as sp:
+            recipe, recipe_bytes = transport.get_recipe(lineage, tag)
+            sp.annotate(bytes=recipe_bytes)
         comparisons = [0]
 
         def tick():

@@ -125,6 +125,7 @@ def gear_hash_pallas(window: jax.Array, prev: jax.Array, first: jax.Array, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
+        name="gear_cdc",        # the kernel's name in a profiler trace
     )(first, window.reshape(rows, LANES), window.reshape(rows, LANES),
       prev.reshape(TILE_ROWS, LANES))
     return jax.lax.bitcast_convert_type(out.reshape(n), jnp.uint32)

@@ -23,6 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import cdc
+from repro.obs import NULL_TRACER, Tracer
 from . import ref
 from .chunk_fp import PAGE_TILE, page_fingerprint_pallas
 from .flash_attention import Q_TILE, flash_attention_pallas
@@ -71,19 +72,36 @@ def _window_len(n: int) -> int:
     return min(WINDOW, max(BLOCK, 1 << (n - 1).bit_length()))
 
 
-def _windows(buf: np.ndarray, impl: Impl, kernel: Callable
-             ) -> Iterator[Tuple[int, int, jax.Array]]:
-    """``(start, length, kernel output)`` for each window of ``buf``."""
+def _windows(buf: np.ndarray, impl: Impl, kernel: Callable,
+             tracer: Tracer = NULL_TRACER
+             ) -> Iterator[Tuple[int, int, int, jax.Array]]:
+    """``(start, length, bytes copied in, kernel output)`` for each window
+    of ``buf``.
+
+    The window is staged (the last one padded on the host) and copied to
+    the device with its halo in a ``scan.h2d`` span, and the kernel runs in
+    a ``scan.kernel`` span; with the tracer enabled each span waits for its
+    work, so it times what the host waited for."""
     interpret = _interpret(impl)
+    block = tracer.enabled
     for start in range(0, buf.size, WINDOW):
-        part = buf[start:start + WINDOW]
-        size = _window_len(part.size)
-        window = part if part.size == size else np.pad(part, (0, size - part.size))
-        prev = (buf[start - TILE_BYTES:start] if start
-                else np.zeros(TILE_BYTES, np.uint8))
-        first = np.array([start == 0], np.int32)
-        yield start, part.size, kernel(window, prev, first,
-                                       interpret=interpret)
+        with tracer.span("scan.h2d"):
+            part = buf[start:start + WINDOW]
+            size = _window_len(part.size)
+            window = (part if part.size == size
+                      else np.pad(part, (0, size - part.size)))
+            prev = (buf[start - TILE_BYTES:start] if start
+                    else np.zeros(TILE_BYTES, np.uint8))
+            first = np.array([start == 0], np.int32)
+            args = jax.device_put((window, prev, first))
+            if block:
+                jax.block_until_ready(args)
+        with tracer.span("scan.kernel"):
+            out = kernel(*args, interpret=interpret)
+            if block:
+                out.block_until_ready()
+        del args                    # the device copies go with the call
+        yield start, part.size, window.nbytes + prev.nbytes + first.nbytes, out
 
 
 def gear_hash(data, impl: Impl) -> np.ndarray:
@@ -92,7 +110,7 @@ def gear_hash(data, impl: Impl) -> np.ndarray:
     if impl == "ref":
         return np.asarray(ref.gear_hash_ref(jnp.asarray(buf)))
     out = np.empty(buf.size, np.uint32)
-    for start, n, h in _windows(buf, impl, gear_hash_pallas):
+    for start, n, _, h in _windows(buf, impl, gear_hash_pallas):
         out[start:start + n] = np.asarray(h)[:n]
     return out
 
@@ -104,32 +122,54 @@ def _boundary_mask(window, prev, first, *, mask_bits: int, interpret: bool):
     return (h & jnp.uint32((1 << mask_bits) - 1)) == 0
 
 
-def chunk_boundaries_accelerated(data, params: cdc.CDCParams,
-                                 impl: Impl) -> List[int]:
+def chunk_boundaries_accelerated(data, params: cdc.CDCParams, impl: Impl,
+                                 tracer: Tracer = NULL_TRACER) -> List[int]:
     """Full CDC: device boundary scan + host min/max pass (DESIGN.md §4).
-    The same cut offsets as ``cdc.chunk_boundaries``."""
+    The same cut offsets as ``cdc.chunk_boundaries``.
+
+    Traced as one ``scan`` span holding, per window, ``scan.h2d``,
+    ``scan.kernel``, ``scan.d2h`` (the mask back to the host) and
+    ``scan.cuts`` (the mask's candidate offsets), then one ``scan.cuts``
+    for the min/max pass; the counts are the ``scan`` span's attributes."""
     if params.algorithm != "gear":
         raise ValueError(
             f"the device scan computes the gear hash, not {params.algorithm!r}")
     buf = _as_bytes(data)
-    if impl == "ref":
-        candidate = np.flatnonzero((gear_hash(buf, impl) & params.mask) == 0)
-    else:
-        kernel = functools.partial(_boundary_mask, mask_bits=params.mask_bits)
-        candidate = np.concatenate(
-            [np.zeros(0, np.int64)]
-            + [np.flatnonzero(np.asarray(mask)[:n]) + start
-               for start, n, mask in _windows(buf, impl, kernel)])
-    return cdc.cuts_from_candidates(candidate + 1, buf.size, params)
+    with tracer.span("scan") as sp:
+        if impl == "ref":
+            found = [np.flatnonzero((gear_hash(buf, impl) & params.mask) == 0)]
+        else:
+            kernel = functools.partial(_boundary_mask,
+                                       mask_bits=params.mask_bits)
+            found = [np.zeros(0, np.int64)]
+            windows = h2d_bytes = d2h_bytes = 0
+            for start, n, copied, mask in _windows(buf, impl, kernel, tracer):
+                with tracer.span("scan.d2h"):
+                    host = np.asarray(mask)
+                with tracer.span("scan.cuts"):
+                    found.append(np.flatnonzero(host[:n]) + start)
+                windows += 1
+                h2d_bytes += copied
+                d2h_bytes += host.nbytes
+            sp.annotate(windows=windows, h2d_bytes=h2d_bytes,
+                        d2h_bytes=d2h_bytes)
+        with tracer.span("scan.cuts"):
+            candidate = np.concatenate(found)
+            cuts = cdc.cuts_from_candidates(candidate + 1, buf.size, params)
+        sp.annotate(candidates=candidate.size, cuts=len(cuts))
+    return cuts
 
 
-def device_scan(impl: Impl) -> Callable[[bytes, cdc.CDCParams], List[int]]:
+def device_scan(impl: Impl, tracer: Tracer = NULL_TRACER
+                ) -> Callable[[bytes, cdc.CDCParams], List[int]]:
     """A boundary scan for ``DedupStore(scan=...)`` / ``ImageClient(scan=...)``
     that runs the gear kernel on the device.  With ``impl="pallas"`` it
     checks here that JAX's first device is a TPU, and raises otherwise: it
-    never falls back to the interpreter or to the host scan."""
+    never falls back to the interpreter or to the host scan.  ``tracer``
+    records each scan's spans (see ``chunk_boundaries_accelerated``)."""
     _interpret(impl)
-    return functools.partial(chunk_boundaries_accelerated, impl=impl)
+    return functools.partial(chunk_boundaries_accelerated, impl=impl,
+                             tracer=tracer)
 
 
 # ---------------------------------------------------------------------------

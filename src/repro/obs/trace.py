@@ -16,12 +16,20 @@ the resulting tree crosses threads but stays one pull.
 Completed **root** spans land in a bounded ring buffer (old pulls fall off,
 memory stays flat); :meth:`Tracer.take` drains them for inspection or for
 ``tools/trace_dump.py``.  Spans serialize to plain dicts
-(:meth:`Span.to_dict`) so a recorded trace survives a JSON round-trip.
+(:meth:`Span.to_dict`, start and duration) so a recorded trace survives a
+JSON round-trip.
+
+A tracer may carry a ``hook``: a callable that takes a span's name and
+returns a context manager, entered around every span the tracer opens.
+Passing ``lambda n: jax.profiler.TraceAnnotation("repro." + n)`` writes
+each span into a profiler trace as well, on the clock of the device's
+events; the caller injects it, so this package stays stdlib-only.
 
 Cost model: tracers are **disabled by default**.  A disabled tracer's
 ``span()`` returns one shared no-op context manager — no allocation, no
-clock read, no lock — which is what keeps "tracing off" indistinguishable
-from "tracing not wired in" (``tests/test_obs.py`` measures it).
+clock read, no lock, no hook call — which is what keeps "tracing off"
+indistinguishable from "tracing not wired in" (``tests/test_obs.py``
+measures it).
 """
 
 from __future__ import annotations
@@ -29,7 +37,7 @@ from __future__ import annotations
 import threading
 import time
 from collections import deque
-from typing import Dict, List, Optional
+from typing import Callable, ContextManager, Dict, List, Optional
 
 __all__ = ["Span", "Tracer", "NULL_TRACER"]
 
@@ -56,13 +64,14 @@ class Span:
 
     def to_dict(self) -> dict:
         return {"name": self.name, "attrs": dict(self.attrs),
-                "duration": self.duration,
+                "start": self.t0, "duration": self.duration,
                 "children": [c.to_dict() for c in self.children]}
 
     @classmethod
     def from_dict(cls, obj: dict) -> "Span":
         sp = cls(obj["name"], dict(obj.get("attrs", {})))
-        sp.t0, sp.t1 = 0.0, float(obj.get("duration", 0.0))
+        sp.t0 = float(obj.get("start", 0.0))
+        sp.t1 = sp.t0 + float(obj.get("duration", 0.0))
         sp.children = [cls.from_dict(c) for c in obj.get("children", ())]
         return sp
 
@@ -95,14 +104,16 @@ _NULL_SPAN = _NullSpanContext()
 
 class _SpanContext:
     """Context manager for one live span: clocks it, maintains the thread's
-    span stack, attaches to the parent (or the ring buffer for roots)."""
+    span stack, attaches to the parent (or the ring buffer for roots), and
+    enters the tracer's hook around it."""
 
-    __slots__ = ("_tracer", "_span", "_parent")
+    __slots__ = ("_tracer", "_span", "_parent", "_hooked")
 
     def __init__(self, tracer: "Tracer", span: Span, parent: Optional[Span]):
         self._tracer = tracer
         self._span = span
         self._parent = parent
+        self._hooked: Optional[ContextManager] = None
 
     def __enter__(self) -> Span:
         tracer = self._tracer
@@ -110,12 +121,17 @@ class _SpanContext:
         if self._parent is None and stack:
             self._parent = stack[-1]
         stack.append(self._span)
+        if tracer.hook is not None:
+            self._hooked = tracer.hook(self._span.name)
+            self._hooked.__enter__()
         self._span.t0 = time.perf_counter()
         return self._span
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         span = self._span
         span.t1 = time.perf_counter()
+        if self._hooked is not None:
+            self._hooked.__exit__(exc_type, exc, tb)
         if exc_type is not None:
             span.attrs.setdefault("error", exc_type.__name__)
         tracer = self._tracer
@@ -132,10 +148,16 @@ class _SpanContext:
 
 
 class Tracer:
-    """Span factory + bounded recorder.  Disabled (free) until asked."""
+    """Span factory + bounded recorder.  Disabled (free) unless asked.
 
-    def __init__(self, enabled: bool = False, capacity: int = 256):
-        self.enabled = enabled  # guarded-by: external(benign bool flip; readers only ever see on/off)
+    ``hook``, when given, is called with each span's name and returns a
+    context manager that is entered around the span (see the module
+    docstring); a disabled tracer never calls it."""
+
+    def __init__(self, enabled: bool = False, capacity: int = 256,
+                 hook: Optional[Callable[[str], ContextManager]] = None):
+        self.enabled = enabled  # guarded-by: external(set at construction; readers only ever see on/off)
+        self.hook = hook
         self._lock = threading.Lock()
         self._roots: deque = deque(maxlen=max(1, capacity))  # guarded-by: _lock
         self._local = threading.local()
@@ -145,18 +167,6 @@ class Tracer:
         if stack is None:
             stack = self._local.stack = []
         return stack
-
-    # ------------------------------------------------------------- control
-
-    def enable(self) -> None:
-        self.enabled = True
-
-    def disable(self) -> None:
-        self.enabled = False
-
-    def clear(self) -> None:
-        with self._lock:
-            self._roots.clear()
 
     # -------------------------------------------------------------- spans
 
@@ -179,11 +189,6 @@ class Tracer:
         return stack[-1] if stack else None
 
     # ----------------------------------------------------------- recorder
-
-    def roots(self) -> List[Span]:
-        """Completed root spans currently held (oldest first), kept."""
-        with self._lock:
-            return list(self._roots)
 
     def take(self) -> List[Span]:
         """Drain and return the recorded root spans."""

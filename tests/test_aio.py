@@ -239,6 +239,47 @@ class TestAsyncServer:
             t.close()
             asrv.stop()
 
+    @pytest.mark.parametrize("workers", [1, 2])
+    def test_queue_wait_counts_time_behind_busy_workers(self, workers):
+        """Two slow requests at once: with one worker the second waits for
+        the first's run, with two neither waits; either way the job
+        counters read through a scrape."""
+        srv, _versions = _seeded_server()
+        asrv = AsyncRegistryServer(srv, workers=workers)
+        real = srv.get_index
+        slow = 0.4
+
+        def slow_get_index(lineage, tag):
+            time.sleep(slow)
+            return real(lineage, tag)
+
+        srv.get_index = slow_get_index
+        t = MuxSocketTransport(asrv.address)
+        try:
+            before = t.scrape_metrics()
+            threads = [threading.Thread(target=t.get_index,
+                                        args=("app", f"v{i}"))
+                       for i in range(2)]
+            for th in threads:
+                th.start()
+            for th in threads:
+                th.join(timeout=30)
+            assert not any(th.is_alive() for th in threads)
+            after = t.scrape_metrics()
+        finally:
+            t.close()
+            asrv.stop()
+
+        def delta(name):
+            return after.value(name, {}) - before.value(name, {})
+
+        assert delta("async_jobs_total") >= 2
+        assert delta("async_job_seconds_total") >= 2 * slow * 0.9
+        if workers == 1:
+            assert delta("async_queue_wait_seconds_total") >= slow / 2
+        else:
+            assert delta("async_queue_wait_seconds_total") < slow / 2
+
     def test_idle_reap_and_transparent_redial(self):
         """The server reaps a connection idle between requests; the shared
         mux connection redials on next use instead of failing the call."""

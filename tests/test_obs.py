@@ -6,6 +6,7 @@ live ``Op.METRICS`` scrape) is asserted in ``tests/test_transport.py``; this
 file tests the ``repro.obs`` package itself.
 """
 
+import contextlib
 import json
 import threading
 import time
@@ -16,6 +17,7 @@ from repro.obs import (LATENCY_BUCKETS, NULL_REGISTRY, NULL_TRACER,
                        MetricsRegistry, MetricsSnapshot, Span, Tracer,
                        check_monotonic, parse_prometheus_text,
                        to_prometheus_text)
+from repro.obs import trace as trace_module
 
 
 # ------------------------------------------------------------------ counters
@@ -274,6 +276,47 @@ class TestTracing:
         walked = [(d, s.name) for d, s in again.walk()]
         assert walked == [(0, "a"), (1, "b")]
 
+    def test_dict_round_trip_keeps_start(self):
+        sp = Span("a", {"k": 1})
+        sp.t0, sp.t1 = 12.5, 13.75
+        child = Span("b")
+        child.t0, child.t1 = 12.75, 13.0
+        sp.children.append(child)
+        again = Span.from_dict(json.loads(json.dumps(sp.to_dict())))
+        assert (again.t0, again.t1) == (12.5, 13.75)
+        assert (again.children[0].t0, again.children[0].duration) == \
+            (12.75, 0.25)
+
+    @pytest.mark.parametrize("fails", [False, True])
+    def test_hook_wraps_every_span_once_nested(self, fails):
+        """An enabled tracer enters its hook around each span, inside the
+        parent's, and hands it the span's outcome."""
+        log = []
+
+        class Hooked:
+            def __init__(self, name):
+                self.name = name
+
+            def __enter__(self):
+                log.append(("enter", self.name))
+
+            def __exit__(self, exc_type, exc, tb):
+                log.append(("exit", self.name,
+                            exc_type.__name__ if exc_type else None))
+                return False
+
+        tr = Tracer(enabled=True, hook=Hooked)
+        with pytest.raises(RuntimeError) if fails else contextlib.nullcontext():
+            with tr.span("outer"):
+                with tr.span("inner"):
+                    if fails:
+                        raise RuntimeError("x")
+        error = "RuntimeError" if fails else None
+        assert log == [("enter", "outer"), ("enter", "inner"),
+                       ("exit", "inner", error), ("exit", "outer", error)]
+        [root] = tr.take()
+        assert [c.name for c in root.children] == ["inner"]
+
 
 # ------------------------------------------------------------ disabled cost
 
@@ -292,11 +335,21 @@ class TestDisabledCost:
             sp.annotate(ignored=True)
         assert NULL_TRACER.take() == []
 
-    def test_disabled_tracing_is_cheap(self):
+    def test_disabled_tracing_is_cheap(self, monkeypatch):
         """Disabled span entry must cost roughly a no-op method call — the
         budget here (2µs/span) is ~100x the observed cost, tight enough to
-        catch an accidental allocation-per-span or clock read."""
-        tr = Tracer(enabled=False)
+        catch an accidental allocation-per-span or clock read.  A disabled
+        tracer with a hook never calls it and never reads the clock."""
+        calls = []
+
+        class Clock:
+            @staticmethod
+            def perf_counter():
+                calls.append("clock")
+                return 0.0
+
+        monkeypatch.setattr(trace_module, "time", Clock)
+        tr = Tracer(enabled=False, hook=lambda name: calls.append(name))
         n = 20_000
         t0 = time.perf_counter()
         for _ in range(n):
@@ -304,6 +357,8 @@ class TestDisabledCost:
                 pass
         per_span = (time.perf_counter() - t0) / n
         assert per_span < 2e-6
+        assert calls == []
+        assert tr.take() == []
 
 
 # ----------------------------------------------------- registry wiring smoke

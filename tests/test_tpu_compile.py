@@ -23,6 +23,7 @@ from repro.kernels import ops
 from repro.kernels.chunk_fp import page_fingerprint_pallas
 from repro.kernels.gear_cdc import TILE_BYTES, gear_hash_pallas
 from repro.launch import compile_cache
+from repro.obs import Tracer
 
 
 @pytest.fixture(scope="module")
@@ -64,7 +65,9 @@ def test_gear_kernel_compiles_for_v5e_at_64mib(one_chip):
     ).lower(_shape((64 << 20,), jnp.uint8, one_chip),
             _shape((TILE_BYTES,), jnp.uint8, one_chip),
             _shape((1,), jnp.int32, one_chip)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "%gear_cdc" in text        # the name a profiler trace shows
 
 
 def test_page_fingerprint_kernel_compiles_for_v5e_at_4096x4096(one_chip):
@@ -113,6 +116,56 @@ def test_device_scan_client_matches_host_scan_and_pulls():
     for i, data in enumerate(versions):
         puller.pull("app", f"v{i}")
         assert puller.materialize("app", f"v{i}") == data
+
+
+@pytest.mark.parametrize("window", [128 << 10, 1 << 20])
+def test_traced_commit_spans_match_recipe_and_store(monkeypatch, window):
+    """A commit through the traced device scan records the span tree the
+    benchmark reads, with counts equal to the recipe's and the store's;
+    its recipe is byte-identical to an untraced and a host-scan commit."""
+    monkeypatch.setattr(ops, "WINDOW", window)
+    tracer = Tracer(enabled=True)
+    traced = ImageClient(None, cdc_params=PARAMS, tracer=tracer,
+                         scan=ops.device_scan("interpret", tracer=tracer))
+    untraced = ImageClient(None, cdc_params=PARAMS,
+                           scan=ops.device_scan("interpret"))
+    host = ImageClient(None, cdc_params=PARAMS)
+    for i, data in enumerate(_versions()[:2]):
+        store = traced.store
+        new_before, dup_before = store.new_chunk_bytes, store.dup_chunk_bytes
+        stored_before = len(store.chunks._mem)
+        recipe = traced.commit("app", f"v{i}", data)
+        assert recipe.to_json() \
+            == untraced.commit("app", f"v{i}", data).to_json() \
+            == host.commit("app", f"v{i}", data).to_json()
+
+        [root] = tracer.take()
+        assert root.name == "commit" and root.attrs["bytes"] == len(data)
+        assert [c.name for c in root.children] == [
+            "scan", "commit.hash", "commit.put", "commit.cdmt"]
+        scan, hashed, put, cdmt = root.children
+        starts = range(0, len(data), window)
+        padded = [ops._window_len(min(window, len(data) - s)) for s in starts]
+        assert [c.name for c in scan.children] == [
+            "scan.h2d", "scan.kernel", "scan.d2h", "scan.cuts"] * len(starts) \
+            + ["scan.cuts"]
+        candidates = np.flatnonzero(
+            (cdc.gear_hash_stream(data) & PARAMS.mask) == 0).size
+        assert scan.attrs == {
+            "windows": len(starts),
+            "h2d_bytes": sum(padded) + len(starts) * (TILE_BYTES + 4),
+            "d2h_bytes": sum(padded), "candidates": candidates,
+            "cuts": len(recipe.fps)}
+        assert hashed.attrs == {"chunks": len(recipe.fps),
+                                "bytes": sum(recipe.sizes)}
+        assert put.attrs == {
+            "new_chunks": len(store.chunks._mem) - stored_before,
+            "new_bytes": store.new_chunk_bytes - new_before,
+            "dup_bytes": store.dup_chunk_bytes - dup_before}
+        assert put.attrs["new_bytes"] + put.attrs["dup_bytes"] == len(data)
+        assert (put.attrs["dup_bytes"] > 0) == (i > 0)
+        assert cdmt.attrs["nodes_created"] > 0
+        assert root.duration >= sum(c.duration for c in root.children)
 
 
 def test_device_scan_refuses_a_non_tpu_platform():

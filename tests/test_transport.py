@@ -29,6 +29,7 @@ from repro.delivery import (AsyncRegistryServer, FetchResult, ImageClient,
                             SocketTransport, SourceLeg, SwarmNode,
                             SwarmTracker, SwarmTransport, TransferReport,
                             WireTransport, swarm_pull, wire)
+from repro.obs import Tracer
 
 PARAMS = cdc.CDCParams(mask_bits=10, min_size=128, max_size=8192)
 P = CDMTParams(window=4, rule_bits=2)
@@ -1053,6 +1054,27 @@ class TestMetricsConformance:
         snap = cl.metrics.snapshot()
         h = snap.histogram("client_pull_seconds", {"transport": "local"})
         assert h is not None and h.count == 1
+
+
+class TestPlanSpans:
+    @pytest.mark.parametrize("kind", TRANSPORTS)
+    def test_plan_pull_children_carry_the_plan_bytes(self, kind):
+        """``plan_pull`` holds its index and recipe fetches as children,
+        each with the bytes the plan accounts; the rest is the compare."""
+        reg = _seed_registry(_versions(3, seed=66))
+        cl = _fresh_client(kind, reg, provisioned_tags=("v2",))
+        cl.tracer = Tracer(enabled=True)
+        try:
+            plan = cl.plan_pull("app", "v2")
+        finally:
+            _cleanup_client(cl)
+        [root] = cl.tracer.take()
+        assert root.name == "plan_pull"
+        assert [(c.name, c.attrs) for c in root.children] == [
+            ("plan_pull.index", {"bytes": plan.index_bytes}),
+            ("plan_pull.recipe", {"bytes": plan.recipe_bytes})]
+        assert plan.index_bytes > 0 and plan.recipe_bytes > 0
+        assert root.duration >= sum(c.duration for c in root.children)
 
 
 class TestMetricsScrape:
