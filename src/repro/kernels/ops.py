@@ -27,7 +27,7 @@ from repro.obs import NULL_TRACER, Tracer
 from . import ref
 from .chunk_fp import PAGE_TILE, page_fingerprint_pallas
 from .flash_attention import Q_TILE, flash_attention_pallas
-from .gear_cdc import BLOCK, TILE_BYTES, gear_hash_pallas
+from .gear_cdc import BLOCK, LANES, TILE_BYTES, gear_hash_pallas
 
 Impl = Literal["pallas", "interpret", "ref"]
 
@@ -36,6 +36,12 @@ Impl = Literal["pallas", "interpret", "ref"]
 # the last window pads to a power of two from BLOCK up, so a process
 # compiles the kernel for at most a dozen lengths, whatever it ingests.
 WINDOW = 64 << 20
+
+# The candidate mask leaves the device bit-packed: 32 stream positions per
+# uint32 word, so one row of 128 words covers 32 rows of the kernel's
+# (rows, 128) output, WORD_BITS * LANES stream positions.
+WORD_BITS = 32
+ROW_SPAN = WORD_BITS * LANES
 
 
 def require_tpu() -> None:
@@ -117,9 +123,35 @@ def gear_hash(data, impl: Impl) -> np.ndarray:
 
 @functools.partial(jax.jit, static_argnames=("mask_bits", "interpret"))
 def _boundary_mask(window, prev, first, *, mask_bits: int, interpret: bool):
-    """Candidate chunk boundaries: low ``mask_bits`` of the rolling hash zero."""
+    """Candidate chunk boundaries of an ``n``-byte window (the low
+    ``mask_bits`` of the rolling hash zero), bit-packed into
+    ``(n // ROW_SPAN, 128)`` uint32 words, n/8 bytes.
+
+    Bit ``k`` of word ``(R, c)`` is window position ``(32 R + k) * 128 + c``:
+    rows ``32 R`` to ``32 R + 31`` of the kernel's ``(rows, 128)`` hash fold
+    into row ``R`` of words, lane by lane.  ``_candidates`` decodes it."""
     h = gear_hash_pallas(window, prev, first, interpret=interpret)
-    return (h & jnp.uint32((1 << mask_bits) - 1)) == 0
+    h = h.reshape(-1, WORD_BITS, LANES)
+    hit = (h & jnp.uint32((1 << mask_bits) - 1)) == 0
+    bit = jnp.uint32(1) << jnp.arange(WORD_BITS, dtype=jnp.uint32)[:, None]
+    return jnp.where(hit, bit, jnp.uint32(0)).sum(axis=1, dtype=jnp.uint32)
+
+
+def _candidates(packed: np.ndarray, n: int) -> Tuple[np.ndarray, int]:
+    """The set bits of a packed mask (``_boundary_mask``'s order) as
+    ascending window positions below ``n``, and the count of non-zero words
+    decoded.  Only rows of words that hold a position below ``n`` are
+    searched, and only their non-zero words are expanded into positions."""
+    words = packed[:-(-n // ROW_SPAN)].reshape(-1)
+    at = np.flatnonzero(words)
+    # position of each word's bit 0: row R = at // 128, lane c = at % 128
+    base = (at // LANES) * ROW_SPAN + at % LANES
+    bits = np.unpackbits(words[at].astype("<u4").view(np.uint8),
+                         bitorder="little").view(bool)
+    k = np.flatnonzero(bits)                  # word index * 32 + bit
+    pos = base[k // WORD_BITS] + k % WORD_BITS * LANES
+    pos.sort()
+    return pos[:np.searchsorted(pos, n)], at.size
 
 
 def chunk_boundaries_accelerated(data, params: cdc.CDCParams, impl: Impl,
@@ -128,9 +160,11 @@ def chunk_boundaries_accelerated(data, params: cdc.CDCParams, impl: Impl,
     The same cut offsets as ``cdc.chunk_boundaries``.
 
     Traced as one ``scan`` span holding, per window, ``scan.h2d``,
-    ``scan.kernel``, ``scan.d2h`` (the mask back to the host) and
-    ``scan.cuts`` (the mask's candidate offsets), then one ``scan.cuts``
-    for the min/max pass; the counts are the ``scan`` span's attributes."""
+    ``scan.kernel``, ``scan.d2h`` (the bit-packed mask back to the host,
+    n/8 bytes for an n-byte window) and ``scan.cuts`` (the candidate offsets
+    decoded from its non-zero words), then one ``scan.cuts`` for the min/max
+    pass; the counts are the ``scan`` span's attributes, ``candidate_words``
+    the non-zero words decoded."""
     if params.algorithm != "gear":
         raise ValueError(
             f"the device scan computes the gear hash, not {params.algorithm!r}")
@@ -142,17 +176,20 @@ def chunk_boundaries_accelerated(data, params: cdc.CDCParams, impl: Impl,
             kernel = functools.partial(_boundary_mask,
                                        mask_bits=params.mask_bits)
             found = [np.zeros(0, np.int64)]
-            windows = h2d_bytes = d2h_bytes = 0
-            for start, n, copied, mask in _windows(buf, impl, kernel, tracer):
+            windows = h2d_bytes = d2h_bytes = candidate_words = 0
+            for start, n, copied, packed in _windows(buf, impl, kernel,
+                                                     tracer):
                 with tracer.span("scan.d2h"):
-                    host = np.asarray(mask)
+                    host = np.asarray(packed)
                 with tracer.span("scan.cuts"):
-                    found.append(np.flatnonzero(host[:n]) + start)
+                    offsets, words = _candidates(host, n)
+                    found.append(offsets + start)
                 windows += 1
                 h2d_bytes += copied
                 d2h_bytes += host.nbytes
+                candidate_words += words
             sp.annotate(windows=windows, h2d_bytes=h2d_bytes,
-                        d2h_bytes=d2h_bytes)
+                        d2h_bytes=d2h_bytes, candidate_words=candidate_words)
         with tracer.span("scan.cuts"):
             candidate = np.concatenate(found)
             cuts = cdc.cuts_from_candidates(candidate + 1, buf.size, params)

@@ -1,5 +1,7 @@
 """Pallas kernels vs pure-jnp oracles: shape/dtype sweeps in interpret mode."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -7,7 +9,8 @@ import pytest
 
 from repro.core import cdc
 from repro.kernels import ops, ref
-from repro.kernels.gear_cdc import BLOCK
+from repro.kernels.gear_cdc import BLOCK, LANES
+from repro.obs import Tracer
 
 
 def _bytes(n, seed=0):
@@ -44,6 +47,64 @@ class TestGearCDC:
         reference = np.asarray(ref.gear_hash_ref(data))
         np.testing.assert_array_equal(full[BLOCK - 2: BLOCK + 2],
                                       reference[BLOCK - 2: BLOCK + 2])
+
+    @pytest.mark.parametrize("n,window,mask_bits,zero_run,edges", [
+        (BLOCK + 777, ops.WINDOW, 8, None, ()),
+        (3 * BLOCK + 4097, BLOCK, 8, None, ()),
+        (2 * BLOCK + 99, BLOCK, 1, (BLOCK - 5000, 2 * BLOCK + 50), ()),
+        (BLOCK + 100, ops.WINDOW, 6, None,
+         (0, 31, 32, 127, 128, 4095, 4096, BLOCK + 99)),
+    ], ids=["odd_length", "windows", "dense", "edges"])
+    def test_packed_mask_decodes_exactly(self, monkeypatch, n, window,
+                                         mask_bits, zero_run, edges):
+        """The bit-packed mask gives exactly the host's candidates: packed in
+        the documented bit order, decoded in order, none from the padding.
+        With ``mask_bits`` 1 every zero byte's hash is a candidate, so the
+        zero run packs whole words of ones and so does the padded tail."""
+        monkeypatch.setattr(ops, "WINDOW", window)
+        buf = _bytes(n, seed=n)
+        if zero_run:
+            buf[slice(*zero_run)] = 0
+        params = cdc.CDCParams(mask_bits=mask_bits, min_size=1,
+                               max_size=1 << 30)   # a cut after each one
+        for p in edges:                   # make position p a candidate
+            buf[p] = next(b for b in range(256) if (
+                cdc.gear_hash_stream(np.r_[buf[max(0, p - 31):p], b])[-1]
+                & params.mask) == 0)
+        want = np.flatnonzero((cdc.gear_hash_stream(buf) & params.mask) == 0)
+        assert set(edges) <= set(want)
+
+        tracer = Tracer(enabled=True)
+        cuts = ops.chunk_boundaries_accelerated(buf, params, "interpret",
+                                                tracer)
+        assert cuts == cdc.chunk_boundaries(buf, params) \
+            == sorted(set(want + 1) | {n})
+
+        # each window's words against the host's mask of the padded stream
+        kernel = functools.partial(ops._boundary_mask, mask_bits=mask_bits)
+        windows = list(ops._windows(buf, "interpret", kernel))
+        padded = [ops._window_len(size) for _, size, _, _ in windows]
+        hit = (cdc.gear_hash_stream(np.pad(buf, (0, sum(padded) - n)))
+               & params.mask) == 0
+        weight = np.uint32(1) << np.arange(ops.WORD_BITS,
+                                           dtype=np.uint32)[:, None]
+        packed, words = [], 0
+        for (start, size, _, out), length in zip(windows, padded):
+            rows = hit[start:start + length].reshape(-1, ops.WORD_BITS, LANES)
+            packed.append(np.asarray(out))
+            np.testing.assert_array_equal(
+                packed[-1], (rows * weight).sum(axis=1, dtype=np.uint32))
+            words += np.count_nonzero(
+                rows[:-(-size // ops.ROW_SPAN)].any(axis=1))
+        dense = mask_bits == 1
+        assert hit[n:].any() == dense     # the padding packs candidates
+        assert any((w == 0xFFFFFFFF).any() for w in packed) == dense
+        [scan] = tracer.take()
+        assert scan.attrs == {
+            "windows": len(windows),
+            "h2d_bytes": sum(copied for _, _, copied, _ in windows),
+            "d2h_bytes": sum(padded) // 8, "candidate_words": words,
+            "candidates": want.size, "cuts": len(cuts)}
 
 
 class TestChunkFingerprint:

@@ -70,6 +70,21 @@ def test_gear_kernel_compiles_for_v5e_at_64mib(one_chip):
     assert "%gear_cdc" in text        # the name a profiler trace shows
 
 
+def test_packed_boundary_mask_compiles_for_v5e_at_64mib(one_chip):
+    """The device scan's jitted step: the kernel, then the candidate mask
+    packed 32 positions to a uint32 word, 8 MiB out for 64 MiB in."""
+    compiled = ops._boundary_mask.lower(
+        _shape((64 << 20,), jnp.uint8, one_chip),
+        _shape((TILE_BYTES,), jnp.uint8, one_chip),
+        _shape((1,), jnp.int32, one_chip),
+        mask_bits=12, interpret=False).compile()
+    out = compiled.out_info
+    assert (out.shape, out.dtype) == (((64 << 20) // 32 // 128, 128),
+                                      jnp.uint32)
+    assert compiled.memory_analysis().output_size_in_bytes == 8 << 20
+    assert "%gear_cdc" in compiled.as_text()
+
+
 def test_page_fingerprint_kernel_compiles_for_v5e_at_4096x4096(one_chip):
     compiled = jax.jit(
         lambda p: page_fingerprint_pallas(p, interpret=False)
@@ -149,12 +164,22 @@ def test_traced_commit_spans_match_recipe_and_store(monkeypatch, window):
         assert [c.name for c in scan.children] == [
             "scan.h2d", "scan.kernel", "scan.d2h", "scan.cuts"] * len(starts) \
             + ["scan.cuts"]
-        candidates = np.flatnonzero(
-            (cdc.gear_hash_stream(data) & PARAMS.mask) == 0).size
+        # the mask leaves the device 32 positions to a uint32 word, bit k
+        # of word (R, c) at position (32 R + k) * 128 + c of its window; the
+        # words of each row R that holds a stream byte are decoded
+        hit = (cdc.gear_hash_stream(
+            data + bytes(starts[-1] + padded[-1] - len(data)))
+            & PARAMS.mask) == 0
+        words = 0
+        for s in starts:
+            rows = -(-min(window, len(data) - s) // ops.ROW_SPAN)
+            words += np.count_nonzero(hit[s:s + rows * ops.ROW_SPAN].reshape(
+                rows, 32, 128).any(axis=1))
         assert scan.attrs == {
             "windows": len(starts),
             "h2d_bytes": sum(padded) + len(starts) * (TILE_BYTES + 4),
-            "d2h_bytes": sum(padded), "candidates": candidates,
+            "d2h_bytes": sum(padded) // 8, "candidate_words": words,
+            "candidates": np.count_nonzero(hit[:len(data)]),
             "cuts": len(recipe.fps)}
         assert hashed.attrs == {"chunks": len(recipe.fps),
                                 "bytes": sum(recipe.sizes)}
