@@ -8,9 +8,23 @@ everything by name:
 
 * ``bench/configs/<config>.json`` holds the configuration as it is run,
   and ``bench/configs/<config>.py`` beside it makes its artifacts from the
-  seed (``make(cfg, seed, spans)`` returns a source whose ``next()`` gives
-  the next version and whose ``reference(version)`` gives the bytes the
-  registry must hold);
+  seed.  ``make(cfg, seed, spans)`` returns a source, having compiled what
+  its versions and saves run, so that nothing compiles in the window.  Its
+  ``next()`` makes the next version inside
+  ``spans.paused("bench.generate")``, so the window's clock stops, and
+  waits there for any device work it starts: the trace counts device time
+  inside ``bench.generate`` as off the clock, not as busy.
+  A version has a ``tag`` and ``artifacts``, pairs ``(lineage, data)``:
+  ``data`` is the artifact's bytes, or its save, a callable of no
+  arguments that returns them (a source whose state lives on the device
+  copies it to the host and serializes it there).  Each publish, in set-up
+  and in the window, calls a save in a ``bench.save`` span with the clock
+  running, then commits and pushes the bytes it returned.
+  ``reference(version)`` gives the bytes the registry must hold, also
+  after ``free()``, which releases the source's state before the check.
+  ``bench/configs/<config>.rehearsal.json`` holds the sizes the CPU
+  rehearsal overrides (``bench/tests/tiny.py``); the chip run never reads
+  it;
 * ``bench/traffic/<traffic>.json`` holds the mix as data, which the one
   loop below reads: ``setup_versions`` published in set-up; ``fleets``,
   groups of pulling clients (``clients`` over ``processes``, their
@@ -39,11 +53,11 @@ compile cache, the artifacts of version 0, the registry, the versions the
 mix publishes in set-up (which compiles or loads every scan shape, since
 every version of a configuration has the same lengths), and the fleet.
 The window then runs rounds until ``--seconds`` have passed and ends with
-the round it started; the clock stops while the benchmark makes the next
-version's bytes.  ``--trace 1`` records the window
-with the JAX profiler and prints the per-layer metrics instead of the
-end-to-end ones.  After the window the device's peak memory is read, the
-program's state is freed, and a sample of the window's versions, drawn
+the round it started; the clock stops while the source makes the next
+version, and runs while a save copies it out.  ``--trace 1`` records the
+window with the JAX profiler and prints the per-layer metrics instead of
+the end-to-end ones.  After the window the device's peak memory is read,
+the program's state is freed, and a sample of the window's versions, drawn
 from the seed, is compared with the plain reference (``reference.py``):
 every cut offset, fingerprint and CDMT root on the publisher, what the
 registry holds and reads back, and the recipe and root of each client
@@ -324,7 +338,11 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
             version = source.next()
             lineages = [lineage for lineage, _ in version.artifacts]
             attempted += len(version.artifacts)
+            sizes = []
             for lineage, data in version.artifacts:
+                if callable(data):
+                    with spans.span("bench.save"):
+                        data = data()
                 with spans.span("bench.commit"):
                     recipe = publisher.commit(lineage, version.tag, data)
                 run.chunks.append(len(recipe.fps))
@@ -333,8 +351,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
                 run.published_bytes += len(data)
                 run.push_wire += report.total_wire_bytes
                 run.push_raw += report.raw_bytes
-            version.artifacts = [(lineage, len(data))
-                                 for lineage, data in version.artifacts]
+                sizes.append((lineage, len(data)))
+            version.artifacts = sizes
             latest = version
             reservoir.offer(version)
             order[version.tag] = len(order)
@@ -526,6 +544,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     if run.trace is not None:
         device["busy_s"] = run.trace.busy()
         device["window_s"] = run.trace.window_s
+        device["generate_busy_s"] = run.trace.generate_busy()
         result["breakdown"] = {
             "device_ops": [list(x) for x in run.trace.device_ops()],
             "idle_gaps": [list(x) for x in run.trace.idle_gaps()]}

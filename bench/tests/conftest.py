@@ -2,6 +2,12 @@
 collects only ``tests/``):
 
     JAX_PLATFORMS=cpu python -m pytest -q bench/tests
+
+Every cell of ``BENCHMARK.json`` is rehearsed here at the sizes of its
+configuration's ``bench/configs/<config>.rehearsal.json``, beside
+``<config>.json`` and ``<config>.py``: a configuration enters by those
+three files, its traffic and metric files and its entries in
+``BENCHMARK.json`` (``tiny.py``).
 """
 
 import os

@@ -52,6 +52,28 @@ def test_busy_is_the_union_of_device_intervals():
     assert t.busy() == pytest.approx(150e-9)          # 50 + 100, ops inside
     assert t.busy_in_spans("bench.scan") == pytest.approx(150e-9)
     assert t.busy_in_spans("bench.push") == 0
+    assert t.generate_busy() == 0
+
+
+def test_device_time_inside_generate_is_off_the_clock():
+    # a trainer step [500,560) while the source makes the next version
+    # [480,580), and a module [570,600) that runs on into the save
+    device = Plane("/device:TPU:0", [
+        Line("XLA Modules", [Ev("jit_a", 100, 50), Ev("jit_step", 500, 60),
+                             Ev("jit_b", 570, 30)])])
+    host = Plane("/host:CPU", [Line("python", [
+        Ev("bench.window", 0, 1000), Ev("bench.scan", 90, 80),
+        Ev("bench.generate", 480, 100), Ev("bench.save", 580, 40)])])
+    t = tr.Trace(Profile([device, host]))
+    assert t.busy() == pytest.approx(70e-9)           # 50 + the 20 past 580
+    assert t.generate_busy() == pytest.approx(70e-9)  # 60 + 10 before 580
+    assert t.busy_in_spans("bench.generate") == 0
+    assert t.busy_in_spans("bench.save") == pytest.approx(20e-9)
+    assert t.busy_in_spans("bench.window") == pytest.approx(70e-9)
+    assert t.busy_in_spans("bench.scan") == pytest.approx(50e-9)
+    gaps = dict(t.idle_gaps())
+    assert gaps["bench.generate"] == pytest.approx(100e-9)
+    assert sum(gaps.values()) + t.busy() == pytest.approx(t.window_s)
 
 
 def test_idle_gaps_go_to_the_innermost_host_span():
@@ -76,6 +98,7 @@ def test_recorded_trace():
     # one _boundary_mask module of 410,846 ns holds the kernel and the mask
     assert t.busy() == pytest.approx(410_846e-9)
     assert t.busy_in_spans("bench.scan") == pytest.approx(410_846e-9)
+    assert t.generate_busy() == 0
     gaps = dict(t.idle_gaps())
     assert set(gaps) == {"bench.commit", "bench.scan"}
     assert sum(gaps.values()) + t.busy() == pytest.approx(t.window_s)

@@ -1,21 +1,34 @@
 """Cells of ``BENCHMARK.json`` cut to a size the CPU rehearses in seconds,
-with the kernels in interpret mode.  Only the tests use these sizes."""
+with the kernels in interpret mode.  Only the tests use these sizes.
+
+A configuration enters the benchmark by files of its own, found by name:
+``bench/configs/<config>.json`` (the configuration as the chip runs it),
+``<config>.py`` beside it (its source of artifacts) and
+``<config>.rehearsal.json``, the keys this rehearsal overrides (a nested
+group updated key by key, any other value replaced), plus its traffic mix
+``bench/traffic/<traffic>.json`` and a reader ``bench/metrics/<metric>.py``
+for each metric.  The chip run never reads the rehearsal file, and a
+configuration without one is refused here rather than rehearsed at its
+full size."""
+
+import json
+import os
 
 import run as bench
 
-SIZES = {
-    "dockerhub-nginx": {"artifact": {"image_bytes": 262144,
-                                     "edits_per_version": 10,
-                                     "insert_delete_pairs": 2,
-                                     "edit_max_bytes": 4096}},
-}
 SEED = 3_000_000_017        # above 2**31: seeds need not fit 32 bits
 SECONDS = 1.0
 
 
 def cell(name: str, root: str = bench.ROOT) -> "bench.Cell":
     c = bench.load_cell(name, root)
-    for key, value in SIZES.get(c.cfg["name"], {}).items():
+    path = os.path.splitext(c.generator)[0] + ".rehearsal.json"
+    if not os.path.isfile(path):
+        raise FileNotFoundError(f"cell {name!r}: its configuration has no "
+                                f"rehearsal sizes; add {path}")
+    with open(path) as f:
+        sizes = json.load(f)
+    for key, value in sizes.items():
         if isinstance(value, dict):
             c.cfg[key].update(value)
         else:

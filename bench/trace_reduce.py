@@ -3,7 +3,10 @@ numbers.
 
 * busy: the union of the intervals in which an operation ran on a device
   (events of the ``XLA Modules`` / ``XLA Ops`` lines of each
-  ``/device:<kind>:<n>`` plane), averaged over the devices;
+  ``/device:<kind>:<n>`` plane), averaged over the devices, less what
+  falls inside a ``bench.generate`` span: the source making the next
+  version off the window's clock, whose device time is counted apart
+  (``generate_busy``);
 * idle gaps: the complement of busy inside the window, each gap attributed
   to the innermost ``bench.*`` host span (``jax.profiler.TraceAnnotation``)
   that holds its midpoint, summed by span name;
@@ -25,6 +28,7 @@ from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 HERE = os.path.dirname(os.path.abspath(__file__))
 DEVICE_LINES = ("XLA Modules", "XLA Ops", "Async XLA Ops")
 WINDOW_SPAN = "bench.window"
+OFF_CLOCK_SPAN = "bench.generate"
 SPAN_PREFIX = "bench."
 
 Interval = Tuple[int, int]          # [start_ns, end_ns)
@@ -62,6 +66,26 @@ def union(intervals: Iterable[Interval]) -> List[Interval]:
 def clip(intervals: Sequence[Interval], lo: int, hi: int) -> List[Interval]:
     return [(max(a, lo), min(b, hi)) for a, b in intervals
             if min(b, hi) > max(a, lo)]
+
+
+def subtract(a: Sequence[Interval], b: Sequence[Interval]
+             ) -> List[Interval]:
+    """The parts of the disjoint sorted intervals ``a`` outside those of
+    ``b``."""
+    out: List[Interval] = []
+    j = 0
+    for lo, hi in a:
+        while j < len(b) and b[j][1] <= lo:
+            j += 1
+        k = j
+        while k < len(b) and b[k][0] < hi:
+            if b[k][0] > lo:
+                out.append((lo, b[k][0]))
+            lo = max(lo, b[k][1])
+            k += 1
+        if lo < hi:
+            out.append((lo, hi))
+    return out
 
 
 def overlap_ns(a: Sequence[Interval], b: Sequence[Interval]) -> int:
@@ -116,6 +140,7 @@ class Trace:
             self.window = (window[0][0], window[-1][1])
         else:
             self.window = (lo or 0, hi or 0)
+        self.off_clock = union(self.spans.get(OFF_CLOCK_SPAN, []))
 
     @classmethod
     def load(cls, path: str) -> "Trace":
@@ -133,16 +158,30 @@ class Trace:
     def window_s(self) -> float:
         return (self.window[1] - self.window[0]) / 1e9
 
+    def _on_clock(self, iv: Sequence[Interval]) -> List[Interval]:
+        """A device's busy intervals inside the window and outside every
+        ``bench.generate`` span."""
+        return subtract(clip(iv, *self.window), self.off_clock)
+
     def busy(self, within: Optional[Sequence[Interval]] = None) -> float:
-        """Seconds a device was busy inside the window (and inside
-        ``within``, when given), averaged over the devices."""
+        """Seconds a device was busy inside the window and on its clock
+        (and inside ``within``, when given), averaged over the devices."""
         if not self.device_busy:
             return 0.0
         total = 0
         for iv in self.device_busy.values():
-            iv = clip(iv, *self.window)
+            iv = self._on_clock(iv)
             total += overlap_ns(iv, union(within)) if within is not None \
                 else sum(b - a for a, b in iv)
+        return total / len(self.device_busy) / 1e9
+
+    def generate_busy(self) -> float:
+        """Seconds a device was busy inside the window's ``bench.generate``
+        spans, off its clock, averaged over the devices."""
+        if not self.device_busy:
+            return 0.0
+        total = sum(overlap_ns(clip(iv, *self.window), self.off_clock)
+                    for iv in self.device_busy.values())
         return total / len(self.device_busy) / 1e9
 
     def busy_in_spans(self, name: str) -> float:
@@ -154,7 +193,7 @@ class Trace:
         where no span is open), largest first; the first device's gaps."""
         if not self.device_busy:
             return []
-        busy = clip(next(iter(self.device_busy.values())), *self.window)
+        busy = self._on_clock(next(iter(self.device_busy.values())))
         edges = [self.window[0]] + [x for iv in busy for x in iv] \
             + [self.window[1]]
         spans = sorted((b - a, a, b, name) for name, iv in self.spans.items()
